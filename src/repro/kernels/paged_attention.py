@@ -8,6 +8,10 @@ table and the per-sequence context lengths ride in as scalar-prefetch
 operands (``pltpu.PrefetchScalarGridSpec``), so the K/V BlockSpec index
 maps resolve the physical page id before each grid step's DMA is issued.
 
+The pool is kv-head-major, ``(pages, kv_heads, page_size, head_dim)``, so
+one grid step's K/V block ``(1, 1, page_size, head_dim)`` is a whole
+``(page_size, head_dim)`` tile of the array.
+
 Grid: ``(n_seqs, n_kv_heads, n_pages)`` with the page axis minor-most so
 the fp32 online-softmax accumulators persist in VMEM scratch across page
 steps (same schedule as ``kernels/flash_attention.py``, which was the
@@ -23,10 +27,15 @@ steps, it is just never read.
 Oracle: ``ref.mha_ref`` on the gathered dense K/V (see
 ``paged_attention_ref`` and ``tests/test_serving.py``).
 
-TPU alignment note: for compiled TPU execution ``head_dim`` should be
-padded to a multiple of 128 and ``page_size`` to a multiple of 8 by the
-caller (the serving engine's pool sizes satisfy this in its TPU
-configuration); interpret mode (CPU tests/benches) takes any shape.
+TPU alignment: Mosaic requires the last two dims of every block to be
+divisible by (8, 128) or to equal the array's own last two dims. The
+kv-head-major K/V block equals the pool's ``(page_size, head_dim)`` and
+the query/output block ``(rep, head_dim)`` equals the blocked query's,
+so any width compiles; a token-major pool, whose block would end in
+``(1, head_dim)``, is refused. For full (8, 128) fp32 and (16, 128) bf16
+tiles, keep ``page_size`` a multiple of 16 and ``head_dim`` a multiple of
+128, as the serving engine's pools are (``tests/test_tpu_compile.py``
+compiles the kernel for a v5e at qwen3_4b widths in both dtypes).
 """
 from __future__ import annotations
 
@@ -63,8 +72,8 @@ def _paged_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(base < ctx)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (rep, Dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (page, Dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                # (page, Dh)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (rep, page)
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
@@ -95,7 +104,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Paged decode attention.
 
     q:             (S, H, Dh)  one query token per sequence
-    k_pages/v_pages: (P, page_size, Kv, Dh) physical page pool
+    k_pages/v_pages: (P, Kv, page_size, Dh) physical page pool
     block_tables:  (S, n_pages) int32 logical->physical page map (pad
                    unused slots with any valid page id, e.g. 0)
     context_lens:  (S,) int32 tokens of context per sequence (0 = lane
@@ -103,7 +112,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     Returns (S, H, Dh) in q's dtype.
     """
     s_n, h, dh = q.shape
-    _, page, kv, _ = k_pages.shape
+    _, kv, page, _ = k_pages.shape
     rep = h // kv
     assert h == kv * rep, (h, kv)
     n_pages = block_tables.shape[1]
@@ -118,10 +127,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, rep, dh),
                          lambda s, g, b, bt, cl: (s, g, 0, 0)),
-            pl.BlockSpec((1, page, 1, dh),
-                         lambda s, g, b, bt, cl: (bt[s, b], 0, g, 0)),
-            pl.BlockSpec((1, page, 1, dh),
-                         lambda s, g, b, bt, cl: (bt[s, b], 0, g, 0)),
+            pl.BlockSpec((1, 1, page, dh),
+                         lambda s, g, b, bt, cl: (bt[s, b], g, 0, 0)),
+            pl.BlockSpec((1, 1, page, dh),
+                         lambda s, g, b, bt, cl: (bt[s, b], g, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rep, dh),
                                lambda s, g, b, bt, cl: (s, g, 0, 0)),
@@ -148,11 +157,15 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     dense per-sequence K/V, masked softmax in fp32. Same contract as
     ``paged_attention``; inactive lanes (context_len 0) return 0."""
     s_n, h, dh = q.shape
-    _, page, kv, _ = k_pages.shape
+    _, kv, page, _ = k_pages.shape
     rep = h // kv
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
-    k = k_pages[block_tables].reshape(s_n, -1, kv, dh)  # (S, n_ctx, Kv, Dh)
-    v = v_pages[block_tables].reshape(s_n, -1, kv, dh)
+
+    def gather(pages):  # (S, n, Kv, page, Dh) -> (S, n_ctx, Kv, Dh)
+        return pages[block_tables].transpose(0, 1, 3, 2, 4).reshape(
+            s_n, -1, kv, dh)
+
+    k, v = gather(k_pages), gather(v_pages)
     kx = jnp.repeat(k, rep, axis=2)                     # (S, n_ctx, H, Dh)
     vx = jnp.repeat(v, rep, axis=2)
     s = jnp.einsum("shd,snhd->shn", q.astype(jnp.float32) * scale,

@@ -11,6 +11,7 @@ from ..core.acl import BusClient
 from ..core.introspect import TRACE_TYPES, summarize_bus, trace_intents
 from ..core.voter import RuleVoter, STANDARD_RULES
 from ..serving.server import build_serving_agent
+from .mesh import configure_compile_cache
 
 
 def main() -> None:
@@ -21,6 +22,7 @@ def main() -> None:
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

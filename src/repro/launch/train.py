@@ -20,6 +20,7 @@ from ..data.pipeline import DataConfig
 from ..optim.optimizer import OptimizerConfig
 from ..train.train_step import StepConfig
 from ..train.trainer import build_env, build_training_agent
+from .mesh import configure_compile_cache
 
 
 def main() -> None:
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--workdir", default=None)
     ap.add_argument("--dual-voter", action="store_true")
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

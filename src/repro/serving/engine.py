@@ -19,10 +19,10 @@ functions:
   for the whole batch's generation to finish, only for the current
   single-token step.
 
-The paged attention itself runs either as the Pallas kernel
-(``kernels/paged_attention.paged_attention``, TPU or ``interpret=True``)
-or the pure-jnp gather oracle (``paged_attention_ref``) — the CPU default,
-since interpret-mode Pallas is orders of magnitude slower than XLA on CPU.
+The paged attention runs as the compiled Pallas kernel
+(``kernels/paged_attention.paged_attention``) on TPU and as the pure-jnp
+gather oracle (``paged_attention_ref``) on every other backend, since
+interpret-mode Pallas is orders of magnitude slower than XLA on CPU.
 
 Scope: the dense decoder family without sliding windows or frontend
 tokens (the serving configs in this repo; asserted in ``__init__``).
@@ -30,7 +30,6 @@ tokens (the serving configs in this repo; asserted in ``__init__``).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -45,6 +44,14 @@ from ..models.params import split_params
 from .kv_pool import KVPool
 
 PAD_POS = 1 << 28  # pad-token position: causally invisible to real queries
+
+
+def _paged_attention(*args, **kw):
+    """The compiled kernel on TPU, the jnp gather elsewhere. Chosen at
+    trace time from the backend; a kernel failure is not caught."""
+    fn = (paged_attention if jax.default_backend() == "tpu"
+          else paged_attention_ref)
+    return fn(*args, **kw)
 
 
 @dataclass
@@ -67,7 +74,6 @@ class PagedEngine:
     def __init__(self, cfg: ArchConfig, *, max_batch: int = 8,
                  num_pages: int = 128, page_size: int = 16,
                  params: Any = None, seed: int = 0,
-                 use_kernel: bool = False, interpret: bool = True,
                  max_pages_per_seq: Optional[int] = None):
         assert cfg.family == "dense", "paged serving: dense decoders only"
         assert cfg.window is None and not cfg.local_global_pattern, \
@@ -78,8 +84,6 @@ class PagedEngine:
             params, _ = split_params(self.model.init(jax.random.PRNGKey(seed)))
         self.params = params
         self.max_batch = max_batch
-        self.use_kernel = use_kernel
-        self.interpret = interpret
         self.pool = KVPool(cfg.n_layers, cfg.n_kv_heads, cfg.head_dim,
                            num_pages=num_pages, page_size=page_size)
         # widest block table any sequence may hold — the decode step's
@@ -97,10 +101,9 @@ class PagedEngine:
     # -- device functions ----------------------------------------------------
     def _attend(self, q, k_pages, v_pages, block_tables, context_lens):
         cfg = self.cfg
-        fn = (partial(paged_attention, interpret=self.interpret)
-              if self.use_kernel else paged_attention_ref)
-        return fn(q, k_pages, v_pages, block_tables, context_lens,
-                  scale=cfg.attn_logit_scale, softcap=cfg.attn_softcap)
+        return _paged_attention(q, k_pages, v_pages, block_tables,
+                                context_lens, scale=cfg.attn_logit_scale,
+                                softcap=cfg.attn_softcap)
 
     def _prefill(self, params, tokens, true_len, *, s_pad: int):
         """tokens (1, s_pad) right-padded; true_len scalar int32.
@@ -155,8 +158,8 @@ class PagedEngine:
             # write each lane's new K/V into its page slot (batched
             # scatter; inactive lanes all hit the null page, whose
             # contents are never read)
-            ka = ka.at[li, slot_pages, slot_offs].set(k[:, 0])
-            va = va.at[li, slot_pages, slot_offs].set(v[:, 0])
+            ka = ka.at[li, slot_pages, :, slot_offs].set(k[:, 0])
+            va = va.at[li, slot_pages, :, slot_offs].set(v[:, 0])
             o = self._attend(q[:, 0], ka[li], va[li],
                              block_tables, attn_lens)
             x = x + attn_out(o[:, None], lp["attn"])

@@ -3,9 +3,11 @@
 Instead of each batch materializing a dense ``(bsz, plen+new)`` cache from
 ``model.init_cache``, the serving engine owns two device arenas
 
-    k, v : (n_layers, num_pages, page_size, n_kv_heads, head_dim)
+    k, v : (n_layers, num_pages, n_kv_heads, page_size, head_dim)
 
-and a host-side **free-list allocator**: each sequence holds an ordered
+(kv-head-major, so each page of one kv head is a whole
+``(page_size, head_dim)`` tile for the paged-attention kernel) and a
+host-side **free-list allocator**: each sequence holds an ordered
 list of physical page ids (its *block table*); logical token ``t`` lives
 at ``(pages[t // page_size], t % page_size)``. Admission reserves
 ``ceil((prompt + max_new) / page_size)`` pages up front (so an admitted
@@ -65,7 +67,7 @@ class KVPool:
         self.head_dim = head_dim
         self.num_pages = num_pages
         self.page_size = page_size
-        shape = (n_layers, num_pages, page_size, n_kv_heads, head_dim)
+        shape = (n_layers, num_pages, n_kv_heads, page_size, head_dim)
         self.k = jnp.zeros(shape, dtype)
         self.v = jnp.zeros(shape, dtype)
         # LIFO free list (page 0 = null page, never handed out). LIFO makes
@@ -193,8 +195,13 @@ class KVPool:
         shape = (self.n_layers, n_p, self.page_size,
                  self.n_kv_heads, self.head_dim)
         idx = jnp.asarray(sb.pages[:n_p], jnp.int32)
-        self.k = self.k.at[:, idx].set(k.reshape(shape).astype(self.k.dtype))
-        self.v = self.v.at[:, idx].set(v.reshape(shape).astype(self.v.dtype))
+
+        def paged(x):  # (L, n_p*page, Kv, Dh) -> (L, n_p, Kv, page, Dh)
+            return x.reshape(shape).transpose(0, 1, 3, 2, 4).astype(
+                self.k.dtype)
+
+        self.k = self.k.at[:, idx].set(paged(k))
+        self.v = self.v.at[:, idx].set(paged(v))
         sb.n_tokens = n_tokens
 
     def swap_arenas(self, k: jax.Array, v: jax.Array) -> None:

@@ -166,7 +166,6 @@ class ContinuousServeEnv:
     num_pages: int = 128
     page_size: int = 16
     max_new_tokens: int = 16
-    use_kernel: bool = False
     seed: int = 0
     max_pages_per_seq: Optional[int] = None
     engine: Optional[PagedEngine] = None
@@ -176,8 +175,7 @@ class ContinuousServeEnv:
             self.engine = PagedEngine(
                 self.cfg, max_batch=self.max_batch,
                 num_pages=self.num_pages, page_size=self.page_size,
-                seed=self.seed, use_kernel=self.use_kernel,
-                max_pages_per_seq=self.max_pages_per_seq)
+                seed=self.seed, max_pages_per_seq=self.max_pages_per_seq)
 
 
 def h_serve_step(args: Dict[str, Any], env: ContinuousServeEnv
@@ -387,14 +385,12 @@ def build_continuous_serving_agent(cfg: ArchConfig, *, bus=None, voters=(),
                                    max_batch: int = 8, num_pages: int = 128,
                                    page_size: int = 16,
                                    max_new_tokens: int = 16,
-                                   use_kernel: bool = False,
                                    max_pages_per_seq: Optional[int] = None,
                                    snapshot_store=None,
                                    agent_id: str = "server") -> LogActAgent:
     env = ContinuousServeEnv(cfg=cfg, max_batch=max_batch,
                              num_pages=num_pages, page_size=page_size,
                              max_new_tokens=max_new_tokens,
-                             use_kernel=use_kernel,
                              max_pages_per_seq=max_pages_per_seq)
     planner = ContinuousServePlanner(max_batch=max_batch,
                                      max_new_tokens=max_new_tokens)

@@ -1,6 +1,10 @@
 """Serving engine tests: paged attention kernel parity, KV pool allocator
 invariants, continuous-batching engine correctness, and LogAct-governed
 admission control."""
+import importlib.util
+from functools import partial
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +17,7 @@ from repro.kernels.paged_attention import paged_attention, paged_attention_ref
 from repro.kernels.ref import mha_ref
 from repro.models.model import Model
 from repro.models.params import split_params
+from repro.serving import engine as engine_mod
 from repro.serving.engine import PagedEngine
 from repro.serving.kv_pool import KVPool, KVPoolError
 from repro.serving.server import (SERVE_ADMISSION_RULES, ServeEnv,
@@ -27,9 +32,9 @@ from repro.serving.server import (SERVE_ADMISSION_RULES, ServeEnv,
 def _paged_case(rng, s_n, h, kv, dh, page, n_pages_pool, ctx_lens):
     """Random pool + block tables realizing the given context lengths."""
     k_pages = jnp.asarray(rng.standard_normal(
-        (n_pages_pool, page, kv, dh)), jnp.float32)
+        (n_pages_pool, kv, page, dh)), jnp.float32)
     v_pages = jnp.asarray(rng.standard_normal(
-        (n_pages_pool, page, kv, dh)), jnp.float32)
+        (n_pages_pool, kv, page, dh)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((s_n, h, dh)), jnp.float32)
     max_pages = -(-max(max(ctx_lens), 1) // page)
     # disjoint, shuffled physical pages per sequence (page 0 = pad)
@@ -46,18 +51,17 @@ def _paged_case(rng, s_n, h, kv, dh, page, n_pages_pool, ctx_lens):
 def _dense_oracle(q, k_pages, v_pages, bt, cls, softcap=None):
     """Per-sequence mha_ref over the gathered dense K/V."""
     s_n, h, dh = q.shape
-    page = k_pages.shape[1]
-    kv = k_pages.shape[2]
+    kv = k_pages.shape[1]
     outs = []
     for i in range(s_n):
         cl = int(cls[i])
         if cl == 0:
             outs.append(jnp.zeros((h, dh), q.dtype))
             continue
-        kd = k_pages[bt[i]].reshape(-1, kv, dh)[:cl]   # (cl, Kv, Dh)
-        vd = v_pages[bt[i]].reshape(-1, kv, dh)[:cl]
-        o = mha_ref(q[i][:, None], kd.transpose(1, 0, 2),
-                    vd.transpose(1, 0, 2), causal=False, softcap=softcap)
+        # (n, Kv, page, Dh) -> (Kv, n*page, Dh), first cl tokens
+        kd = k_pages[bt[i]].transpose(1, 0, 2, 3).reshape(kv, -1, dh)[:, :cl]
+        vd = v_pages[bt[i]].transpose(1, 0, 2, 3).reshape(kv, -1, dh)[:, :cl]
+        o = mha_ref(q[i][:, None], kd, vd, causal=False, softcap=softcap)
         outs.append(o[:, 0])
     return jnp.stack(outs)
 
@@ -298,20 +302,70 @@ def test_admission_control_prompt_budget(serve_cfg):
     assert pl.rejected == ["big"]
 
 
-def test_engine_with_interpret_kernel(serve_cfg, oracle_env):
+def test_engine_with_interpret_kernel(serve_cfg, oracle_env, monkeypatch):
     """The Pallas kernel path (interpret mode) generates the same tokens
     as the jnp paged reference inside the full engine."""
     prompt = [3, 1, 4, 1, 5]
-    eng_ref = PagedEngine(serve_cfg, max_batch=2, num_pages=16, page_size=8,
-                          params=oracle_env.params, use_kernel=False)
-    eng_ker = PagedEngine(serve_cfg, max_batch=2, num_pages=16, page_size=8,
-                          params=oracle_env.params, use_kernel=True,
-                          interpret=True)
     outs = []
-    for eng in (eng_ref, eng_ker):
+    for attend in (paged_attention_ref,
+                   partial(paged_attention, interpret=True)):
+        # the engine traces its decode step on first use, so the
+        # attention it finds then is the one compiled in
+        monkeypatch.setattr(engine_mod, "_paged_attention", attend)
+        eng = PagedEngine(serve_cfg, max_batch=2, num_pages=16, page_size=8,
+                          params=oracle_env.params)
         assert eng.admit("r", prompt, 4)
         done = []
         while eng.n_inflight:
             done += eng.step()
         outs.append(done[0].tokens)
     assert outs[0] == outs[1]
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py: its phases at smoke size (everything but the TPU check)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def chip_smoke():
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_phases_at_smoke_size(serve_cfg, chip_smoke):
+    problems, facts = chip_smoke.run(
+        serve_cfg, on_chip=False, n_requests=6, max_new_tokens=4,
+        max_batch=4, num_pages=64, max_pages_per_seq=8,
+        prompt_pages=(1, 3))
+    assert problems == []
+    assert facts["tokens"] == 6 * 4
+    assert facts["governed_steps"] == facts["decode_steps"] > 0
+    assert not facts["kernel_in_decode_step"]   # CPU serves the jnp gather
+    assert facts["kernel_vs_ref_rel_err"] <= 1e-5
+
+
+def test_chip_smoke_refuses_cpu(chip_smoke, capsys):
+    assert chip_smoke.main() != 0
+    out = capsys.readouterr()
+    assert '"ok"' not in out.out
+    assert "no TPU found" in out.err
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    from repro.launch.mesh import DEFAULT_COMPILE_CACHE, configure_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was  # left to JAX
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert configure_compile_cache() == str(DEFAULT_COMPILE_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(
+            DEFAULT_COMPILE_CACHE)
+        assert DEFAULT_COMPILE_CACHE == \
+            Path(__file__).resolve().parents[1] / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
